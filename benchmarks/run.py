@@ -17,6 +17,7 @@ persist machine-readable BENCH_*.json rows (benchmarks/common.py schema;
 benchmarks/check_bench_schema.py validates them in CI).
 """
 import os
+import sys
 import time
 import traceback
 
@@ -59,15 +60,20 @@ def main() -> None:
         ('roofline', roofline.run, {}),
     ]
     t00 = time.time()
+    failed = []
     for name, fn, kw in jobs:
         t0 = time.time()
         try:
             fn(**kw)
             print(f'# {name} done in {time.time()-t0:.1f}s', flush=True)
         except Exception as e:
+            # keep running the other jobs; the exit code reports this one
             traceback.print_exc()
             print(f'{name},0.0,ERROR {type(e).__name__}: {e}', flush=True)
+            failed.append(name)
     print(f'# total {time.time()-t00:.1f}s')
+    if failed:
+        sys.exit(f'# failed jobs: {", ".join(failed)}')
 
 
 if __name__ == '__main__':

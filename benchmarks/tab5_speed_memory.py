@@ -12,8 +12,8 @@ O(p) — the paper's Tab. 5 memory column).
 backends (tree | flat | pallas) over pytrees of growing leaf count at fixed
 total p: the tree backend pays per-leaf einsum dispatch that grows with leaf
 count, the flat backend is one fused matmul per pass regardless, and pallas
-off-TPU runs in interpret mode (correctness reference, not a speed number —
-its compiled-TPU cost model is in benchmarks/roofline.py). Each row also
+(timed only on a TPU, the one backend its kernels compile for; its cost
+model is in benchmarks/roofline.py). Each row also
 reports the resident sketch-buffer memory (C plus the whitened factor B),
 for f32 and — on the flat family — bf16 sketch storage, so the
 docs/backends.md table cites reproducible numbers. All apply timings go
@@ -218,25 +218,20 @@ def run_backend_apply(leaf_counts=(2, 8, 32), p_total=1 << 18, k=32,
                           tree_random_like(jax.random.PRNGKey(0), params))
         backends = [('tree', 'tree'), ('flat', 'flat'),
                     ('flat_bf16', FlatBackend(sketch_dtype=jnp.bfloat16))]
-        # off-TPU, pallas runs in interpret mode (~13 s/apply): one
-        # correctness data point at the largest tree is enough there.
-        if include_pallas and (jax.default_backend() == 'tpu'
-                               or n_leaves == leaf_counts[-1]):
-            backends.append(('pallas', PallasBackend(interpret=None,
-                                                     block_p=4096)))
+        # the Pallas kernels compile only for a TPU; elsewhere there is no
+        # pallas row (interpret mode is a test path, not a timing)
+        if include_pallas and jax.default_backend() == 'tpu':
+            backends.append(('pallas', PallasBackend(block_p=4096)))
         for backend, be in backends:
             solver = NystromIHVP(k=k, rho=1e-2, backend=be)
             sketch = solver.prepare(hvp, idxr, jax.random.PRNGKey(1))
             sketch = jax.block_until_ready(sketch)
             apply_fn = jax.jit(solver.apply_matrix)
             jax.block_until_ready(apply_fn(sketch, v1))     # warmup/compile
-            # interpret-mode pallas is a correctness path; don't loop on it
-            n = 1 if (backend == 'pallas'
-                      and jax.default_backend() != 'tpu') else reps
             t0 = time.time()
-            for _ in range(n):
+            for _ in range(reps):
                 jax.block_until_ready(apply_fn(sketch, v1))
-            per = (time.time() - t0) / n
+            per = (time.time() - t0) / reps
             out[('apply', backend, n_leaves)] = per
             if rows is not None:
                 rows.append(bench_row(
@@ -248,8 +243,7 @@ def run_backend_apply(leaf_counts=(2, 8, 32), p_total=1 << 18, k=32,
             emit('tab5_backend_apply', per * 1e6,
                  f'backend={backend} n_leaves={n_leaves} p={p_count} k={k} '
                  f'apply_wall_s={per:.6f} '
-                 f'sketch_MB={_sketch_bytes(sketch) / 1e6:.1f}'
-                 + (' (interpret mode)' if n == 1 else ''))
+                 f'sketch_MB={_sketch_bytes(sketch) / 1e6:.1f}')
         tree_t = out[('apply', 'tree', n_leaves)]
         flat_t = out[('apply', 'flat', n_leaves)]
         emit('tab5_backend_apply', 0.0,

@@ -41,11 +41,13 @@ __all__ = ['OpRecord', 'DotRecord', 'TransferRecord', 'ConstRecord',
 
 # ---------------------------------------------------------------------------
 # Canonical collective naming.  Three spellings reach us: jaxpr primitive
-# names (psum / psum2 / all_gather ...), StableHLO ops (stablehlo.all_reduce),
+# names (psum / psum2 / all_gather ...; ``lax.psum`` inside ``shard_map``
+# traces as psum_invariant), StableHLO ops (stablehlo.all_reduce),
 # and compiled-HLO ops (all-reduce).  Contracts accept any alias.
 # ---------------------------------------------------------------------------
 _CANONICAL = {
-    'psum': 'all-reduce', 'psum2': 'all-reduce', 'all_reduce': 'all-reduce',
+    'psum': 'all-reduce', 'psum2': 'all-reduce', 'psum_invariant': 'all-reduce',
+    'all_reduce': 'all-reduce',
     'all-reduce': 'all-reduce', 'pmax': 'all-reduce', 'pmin': 'all-reduce',
     'all_gather': 'all-gather', 'all-gather': 'all-gather',
     'reduce_scatter': 'reduce-scatter', 'reduce-scatter': 'reduce-scatter',
@@ -183,13 +185,13 @@ class ProgramReport:
 # jaxpr walking
 # ---------------------------------------------------------------------------
 def _sub_jaxprs(params: Mapping[str, Any]):
-    from jax import core as jcore
+    from jax.extend.core import ClosedJaxpr, Jaxpr
     for value in params.values():
         items = value if isinstance(value, (list, tuple)) else (value,)
         for item in items:
-            if isinstance(item, jcore.ClosedJaxpr):
+            if isinstance(item, ClosedJaxpr):
                 yield item.jaxpr
-            elif isinstance(item, jcore.Jaxpr):
+            elif isinstance(item, Jaxpr):
                 yield item
 
 
@@ -235,12 +237,27 @@ _MLIR_DTYPE_BYTES = {'f64': 8, 'f32': 4, 'f16': 2, 'bf16': 2, 'i64': 8,
                      'ui64': 8, 'i32': 4, 'ui32': 4, 'i16': 2, 'ui16': 2,
                      'i8': 1, 'ui8': 1, 'i1': 1, 'f8e4m3fn': 1, 'f8e5m2': 1}
 
-_HLO_COLLECTIVES = ('all-reduce', 'all-gather', 'reduce-scatter',
-                    'all-to-all', 'collective-permute')
+HLO_COLLECTIVES = ('all-reduce', 'all-gather', 'reduce-scatter',
+                   'all-to-all', 'collective-permute')
 _HLO_LINE_RE = re.compile(
-    r'^\s*(?:ROOT\s+)?%[\w.-]+\s*=\s*(\([^)]*\)|[^=(]+?)\s+('
-    + '|'.join(_HLO_COLLECTIVES) + r')(?:-start|-done)?\(')
-_HLO_SHAPE_RE = re.compile(r'(\w+)\[([\d,]*)\]')
+    r'^\s*(?:ROOT\s+)?%[\w.-]+\s*=\s*([^=]+?)\s+('
+    + '|'.join(HLO_COLLECTIVES) + r')(?:-start|-done)?\(')
+#: (dtype, dims) of each array type in an HLO type string; a TPU layout
+#: suffix such as ``{1,0:T(4,128)S(1)}`` follows the dims and is ignored
+HLO_SHAPE_RE = re.compile(r'(\w+)\[([\d,]*)\]')
+
+
+def hlo_collective_ops(text: str):
+    """(kind, result type string, line) of every collective in compiled
+    HLO text. An async pair counts once: ``-start`` is kept, ``-done``
+    (the same transfer) skipped. The one reader of the collective-line
+    format, shared with ``repro.launch.analysis.collective_bytes``."""
+    for line in text.splitlines():
+        if '-done(' in line:
+            continue
+        m = _HLO_LINE_RE.match(line)
+        if m:
+            yield m.group(2), m.group(1), line
 
 
 def _tensor_on_line(line: str) -> tuple[tuple[int, ...], str]:
@@ -286,20 +303,15 @@ def _parse_stablehlo(text: str, report: ProgramReport) -> None:
 
 
 def _parse_hlo(text: str, report: ProgramReport) -> None:
-    for line in text.splitlines():
-        if '-done(' in line:
-            continue                      # same transfer as its -start
-        m = _HLO_LINE_RE.match(line)
-        if not m:
-            continue
-        sm = _HLO_SHAPE_RE.search(m.group(1))
+    for kind, type_str, line in hlo_collective_ops(text):
+        sm = HLO_SHAPE_RE.search(type_str)
         shape: tuple[int, ...] = ()
         dtype = '?'
         if sm:
             dtype = sm.group(1)
             shape = tuple(int(d) for d in sm.group(2).split(',') if d)
         report.collectives.append(OpRecord(
-            kind=canonical_collective(m.group(2)), dtype=dtype, shape=shape,
+            kind=canonical_collective(kind), dtype=dtype, shape=shape,
             source='hlo', detail=line.strip()[:120]))
 
 

@@ -33,11 +33,11 @@ A backend owns the operand representation and fuses the p-pass. Four ship
   k (resp. k×k) floats across the mesh. Leaves replicated along some mesh
   axes are down-weighted by 1/replication so the psum never overcounts.
   No parameter leaf is ever all-gathered.
-* ``pallas``       — the flat buffer in the kernel-tiled (p, k) transpose;
-  ``gram``/``ctv`` and the fused Woodbury pass-2 (``v/ρ + C w``) dispatch
-  to the hand-tiled TPU kernels in ``repro.kernels`` (one HBM read of C per
-  pass, VMEM-resident k-tile accumulator). Off-TPU the kernels execute in
-  interpret mode — bit-faithful but slow; select it off-TPU only in tests.
+* ``pallas``       — ``flat``'s (k, p) buffer, read in place by the
+  hand-tiled TPU kernels in ``repro.kernels`` for ``gram``/``ctv`` and the
+  fused Woodbury pass-2 (``v/ρ + C w``): one HBM read of C per pass,
+  VMEM-resident k-wide accumulator. The kernels compile only for a TPU;
+  tests run them in interpret mode with an explicit ``interpret=True``.
 
 All flat-family backends take ``sketch_dtype=`` (default f32): the fused
 sketch buffer — the dominant O(kp) state — may be stored in bf16 while
@@ -149,9 +149,8 @@ def flatten_sketch(C: PyTree, dtype=jnp.float32) -> jax.Array:
 
     Sketch-major (k, p) is the cache-friendly layout for XLA-on-CPU/GPU:
     every contraction streams contiguous p-rows (measured 35× over the
-    transposed layout for Cᵀv at p=8M on CPU). The Pallas kernels tile the
-    transposed (p, k) layout instead — PallasBackend transposes once at
-    prepare()."""
+    transposed layout for Cᵀv at p=8M on CPU). The Pallas kernels stream
+    this same buffer, one (k, block_p) slab per grid step."""
     cols = [c.astype(dtype).reshape(c.shape[0], -1)
             for c in jax.tree.leaves(C)]
     return jnp.concatenate(cols, axis=1)
@@ -360,76 +359,57 @@ class FlatBackend:
 
 @dataclasses.dataclass(frozen=True)
 class PallasBackend(FlatBackend):
-    """Fused buffer + Pallas TPU kernels for the C-streaming passes.
+    """FlatBackend's (k, p) buffer + Pallas TPU kernels for the C-streaming
+    reduction passes.
 
-    The operand is the kernel-tiled (p, k) layout (k padded to the 128-lane
-    width inside the kernels) — the transpose of FlatBackend's buffer, taken
-    once at prepare(). ``interpret=None`` lets the kernel wrappers pick
-    (compiled on TPU, interpret elsewhere); ``block_p`` is the p-tile the
-    grid streams. ``cv``/``mul_right``/``cross`` stay on XLA: they are
-    p-output or k×k-output matmuls XLA already tiles well; gram/ctv/combine
-    are the C-streaming reduction passes the kernels were built for.
-    ``sketch_dtype=bf16`` composes: the kernels upcast each streamed slab to
-    f32 in VMEM, so HBM traffic and storage halve while accumulation stays
-    f32.
+    The kernels read the sketch-major buffer ``prepare_operand`` builds in
+    place — no padded or transposed copy of C. ``interpret`` is the
+    kernels' explicit mode: ``None`` (default) compiles them, which needs a
+    TPU and raises elsewhere; tests pass ``interpret=True``. ``block_p`` is
+    the p-tile the grid streams. gram/ctv/combine (and their block forms)
+    run the kernels; ``cv``/``mul_right``/``cross``/``cm`` stay on
+    FlatBackend's XLA matmuls, which already tile those p-output and
+    k×k-output products well. ``sketch_dtype=bf16`` composes: the kernels
+    upcast each streamed slab to f32 in VMEM, so HBM traffic and storage
+    halve while accumulation stays f32.
     """
     name = 'pallas'
     interpret: bool | None = None
     block_p: int = 1024
 
-    def prepare_operand(self, C: PyTree) -> jax.Array:
-        return flatten_sketch(C, dtype=self.sketch_dtype).T   # (p, k)
-
-    def ctv(self, Cpk: jax.Array, vf: jax.Array) -> jax.Array:
+    def ctv(self, Ckp: jax.Array, vf: jax.Array) -> jax.Array:
         from repro.kernels import ops
-        return ops.woodbury_ctv(Cpk, vf, block_p=self.block_p,
+        return ops.woodbury_ctv(Ckp, vf, block_p=self.block_p,
                                 interpret=self.interpret)
 
-    def cv(self, Cpk: jax.Array, w: jax.Array) -> jax.Array:
-        return jnp.einsum('pk,k->p', Cpk, w,
-                          preferred_element_type=jnp.float32)
-
-    def gram(self, Cpk: jax.Array) -> jax.Array:
+    def gram(self, Ckp: jax.Array) -> jax.Array:
         from repro.kernels import ops
-        return ops.nystrom_gram(Cpk, block_p=self.block_p,
+        return ops.nystrom_gram(Ckp, block_p=self.block_p,
                                 interpret=self.interpret)
 
-    def cross(self, Apk: jax.Array, Bpk: jax.Array) -> jax.Array:
-        return jnp.einsum('pk,pj->kj', Apk, Bpk,
-                          preferred_element_type=jnp.float32)
-
-    def mul_right(self, Cpk: jax.Array, M: jax.Array) -> jax.Array:
-        out = jnp.einsum('pk,kj->pj', Cpk, M,                 # (p, j)
-                         preferred_element_type=jnp.float32)
-        return out.astype(self.sketch_dtype)
-
-    def slice_k(self, Cpk: jax.Array, start: int, width: int) -> jax.Array:
-        return jax.lax.slice_in_dim(Cpk, start, start + width, axis=1)
-
-    def combine(self, Cpk: jax.Array, w: jax.Array, vf: jax.Array,
+    def combine(self, Ckp: jax.Array, w: jax.Array, vf: jax.Array,
                 rho: float) -> jax.Array:
         from repro.kernels import ops
-        # woodbury_apply computes v/ρ − C w̃/ρ²; w̃ = −ρ² w gives v/ρ + C w.
-        return ops.woodbury_apply(Cpk, -(rho * rho) * w, vf, rho,
+        # woodbury_apply computes v/ρ − Cᵀw̃/ρ²; w̃ = −ρ² w gives v/ρ + Cᵀw.
+        return ops.woodbury_apply(Ckp, -(rho * rho) * w, vf, rho,
                                   block_p=self.block_p,
                                   interpret=self.interpret)
 
-    # -- matrix-valued queries: the kernels take (p, m) blocks natively -----
-    def ctm(self, Cpk: jax.Array, Vm: jax.Array) -> jax.Array:
+    # -- matrix-valued queries: the kernels stream query-major (m, p)
+    # blocks, so the (p, m) block is handed over transposed. XLA lays the
+    # block out p-minor, which makes each .T a bitcast, not an HBM copy
+    # (tests/test_tpu_compile.py compiles the block apply to check) -------
+    def ctm(self, Ckp: jax.Array, Vm: jax.Array) -> jax.Array:
         from repro.kernels import ops
-        return ops.woodbury_ctv(Cpk, Vm, block_p=self.block_p,
+        return ops.woodbury_ctv(Ckp, Vm.T, block_p=self.block_p,
                                 interpret=self.interpret)
 
-    def cm(self, Cpk: jax.Array, W: jax.Array) -> jax.Array:
-        return jnp.einsum('pk,km->pm', Cpk, W,
-                          preferred_element_type=jnp.float32)
-
-    def combinem(self, Cpk: jax.Array, W: jax.Array, Vm: jax.Array,
+    def combinem(self, Ckp: jax.Array, W: jax.Array, Vm: jax.Array,
                  rho: float) -> jax.Array:
         from repro.kernels import ops
-        return ops.woodbury_apply(Cpk, -(rho * rho) * W, Vm, rho,
+        return ops.woodbury_apply(Ckp, -(rho * rho) * W, Vm.T, rho,
                                   block_p=self.block_p,
-                                  interpret=self.interpret)
+                                  interpret=self.interpret).T
 
 
 # ---------------------------------------------------------------------------

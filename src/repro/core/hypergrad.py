@@ -195,7 +195,7 @@ class HypergradConfig:
     #   tree         = pytree einsums, sharding-transparent, the default
     #   flat         = fused (k, p) buffer, one XLA matmul per contraction
     #   flat_sharded = per-device fused shards + psum (needs mesh/specs)
-    #   pallas       = flat buffer + TPU kernels (interpret fallback off-TPU)
+    #   pallas       = flat buffer + TPU kernels (compiled; TPU only)
     mesh: Any = None              # flat_sharded: the step's jax Mesh
     param_specs: Any = None       # flat_sharded: PartitionSpec pytree
     sketch_dtype: str | None = None  # flat family: 'bfloat16' halves sketch

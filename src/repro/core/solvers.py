@@ -66,7 +66,7 @@ Contraction backends: every tall-skinny contraction in the Nyström hot path
                  PartitionSpecs; never all-gathers a parameter leaf.
   'pallas'       flat buffer with the gram / Cᵀv / fused-apply passes in
                  the hand-tiled Pallas TPU kernels (repro.kernels) — one
-                 HBM read of C per pass. Interpret-mode fallback off-TPU.
+                 HBM read of C per pass. Compiled for the TPU only.
 
 Sharding: solvers are pure jax; under pjit with backend='tree', C (leading-k
 parameter pytree) inherits the parameter sharding and CᵀC / Cᵀv lower to
@@ -153,8 +153,8 @@ class NystromSketch:
     ``C`` is the backend-native sketch operand: a leading-k parameter pytree
     for backend='tree', the fused sketch-major (k, p) buffer for
     backend='flat', the per-device ``ShardedOperand`` (local fused buffer +
-    psum weights) for backend='flat_sharded', or the kernel-tiled (p, k)
-    transpose for backend='pallas' — there is no separate unflatten spec;
+    psum weights) for backend='flat_sharded' (and the same (k, p) buffer
+    for backend='pallas') — there is no separate unflatten spec;
     apply() reads the output structure off the incoming ``v``.
 
     ``B``/``gram_B`` is the numerically-stable whitened form of H_k

@@ -62,9 +62,8 @@ def compressed_all_reduce(contribs: jax.Array, mesh,
     what makes this a reduction rather than a scale-by-n."""
     from jax.sharding import PartitionSpec as P
 
-    from repro.distributed.ctx import shard_map
     rest = (None,) * (contribs.ndim - 1)
-    return shard_map(
+    return jax.shard_map(
         lambda v: compressed_psum(v.astype(jnp.float32).sum(axis=0),
                                   axis_name),
         mesh=mesh, in_specs=P(axis_name, *rest),

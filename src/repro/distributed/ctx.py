@@ -22,32 +22,6 @@ import contextlib
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-# jax.shard_map landed in 0.5.x; on 0.4.x it lives in jax.experimental.
-# Every call site in this repo goes through this name so the version split
-# stays in one place.
-if hasattr(jax, 'shard_map'):
-    shard_map = jax.shard_map
-else:
-    from jax.experimental.shard_map import shard_map  # noqa: F401  (jax<0.5)
-
-
-def _rep_check_kwarg() -> str | None:
-    """The kwarg that disables shard_map's output-replication checker —
-    renamed check_rep → check_vma across jax versions; probed once here so
-    call sites stay version-agnostic."""
-    import inspect
-    try:
-        params = inspect.signature(shard_map).parameters
-    except (TypeError, ValueError):          # pragma: no cover
-        return None
-    for name in ('check_rep', 'check_vma'):
-        if name in params:
-            return name
-    return None
-
-
-_REP_KWARG = _rep_check_kwarg()
-
 
 def shard_map_unchecked(f, mesh: Mesh, in_specs, out_specs):
     """``shard_map`` with the static replication checker off.
@@ -59,9 +33,8 @@ def shard_map_unchecked(f, mesh: Mesh, in_specs, out_specs):
     from replicated inputs and psum'd reductions). The collective structure
     is unchanged; only the static proof obligation is waived.
     """
-    kw = {_REP_KWARG: False} if _REP_KWARG else {}
-    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     **kw)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 _MESH: Mesh | None = None

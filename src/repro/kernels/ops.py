@@ -1,13 +1,16 @@
 """jit'd public wrappers for the Pallas kernels.
 
-``interpret`` defaults to True off-TPU so the same call sites work in CI;
-on TPU backends the kernels compile to Mosaic. ``use_pallas`` model configs
-route through here (serving fast path); the dry-run keeps the XLA twins so
-cost_analysis sees real FLOPs (DESIGN.md §3).
+``interpret`` is an explicit choice: ``interpret=True`` runs a kernel body
+in the Pallas interpreter (CPU tests), ``False`` compiles it to Mosaic, and
+the default ``None`` means "compiled" — which only a TPU backend can do, so
+off the TPU it raises instead of quietly interpreting. ``use_pallas`` model
+configs route through here; the dry-run keeps the XLA twins so
+cost_analysis sees real FLOPs.
+
+The Nyström kernels take the sketch-major (k, p) buffer ``FlatBackend``
+builds, unpadded and untransposed.
 """
 from __future__ import annotations
-
-import functools
 
 import jax
 
@@ -31,53 +34,59 @@ KERNEL_CONTRACT = Contract(
 )
 
 
-@functools.cache
-def _default_interpret() -> bool:
-    return jax.default_backend() != 'tpu'
+def _interpret(interpret: bool | None) -> bool:
+    """``None`` → compile for the TPU; raise where there is none."""
+    if interpret is not None:
+        return interpret
+    backend = jax.default_backend()
+    if backend != 'tpu':
+        raise RuntimeError(
+            f'Pallas kernels compile only for a TPU, and the JAX backend is '
+            f'{backend!r}; pass interpret=True to run them in the Pallas '
+            'interpreter instead')
+    return False
 
 
 def nystrom_gram(C, *, block_p: int = 1024, interpret: bool | None = None):
-    return _gram(C, block_p=block_p,
-                 interpret=_default_interpret() if interpret is None else interpret)
+    """CᵀC for the sketch-major C (k, p) → (k, k)."""
+    return _gram(C, block_p=block_p, interpret=_interpret(interpret))
 
 
 def nystrom_cross(A, B, *, block_p: int = 1024, interpret: bool | None = None):
-    """AᵀB for A (p, k), B (p, m) → (k, m): the gram kernel's two-operand
-    form (batched Cᵀv over an m-query block, one C-read)."""
-    return _cross(A, B, block_p=block_p,
-                  interpret=_default_interpret() if interpret is None else interpret)
+    """A Bᵀ for A (k, p), a query-major block B (m, p) → (k, m): the gram
+    kernel's two-operand form (batched Cᵀv over m queries, one C-read)."""
+    return _cross(A, B, block_p=block_p, interpret=_interpret(interpret))
 
 
 def woodbury_ctv(C, v, *, block_p: int = 1024, interpret: bool | None = None):
-    """Cᵀv. v may be (p,) → (k,) or a (p, m) query block → (k, m)."""
-    return _wctv(C, v, block_p=block_p,
-                 interpret=_default_interpret() if interpret is None else interpret)
+    """Cᵀv for C (k, p). v may be (p,) → (k,) or a query-major block
+    (m, p) → (k, m)."""
+    return _wctv(C, v, block_p=block_p, interpret=_interpret(interpret))
 
 
 def woodbury_apply(C, w, v, rho: float, *, block_p: int = 1024,
                    interpret: bool | None = None):
-    """v/ρ − Cw/ρ². Vector (w (k,), v (p,)) or block (w (k, m), v (p, m))."""
+    """v/ρ − Cᵀw/ρ² for C (k, p). Vector (w (k,), v (p,)) or query-major
+    block (w (k, m), v (m, p))."""
     return _wapply(C, w, v, rho, block_p=block_p,
-                   interpret=_default_interpret() if interpret is None else interpret)
+                   interpret=_interpret(interpret))
 
 
 def rmsnorm(x, scale, eps: float = 1e-5, *, interpret: bool | None = None):
-    return _rmsnorm(x, scale, eps,
-                    interpret=_default_interpret() if interpret is None else interpret)
+    return _rmsnorm(x, scale, eps, interpret=_interpret(interpret))
 
 
 def flash_attention(q, k, v, *, causal: bool = True, scale: float | None = None,
                     q_block: int = 512, k_block: int = 512,
                     interpret: bool | None = None):
     return _flash(q, k, v, causal=causal, scale=scale, q_block=q_block,
-                  k_block=k_block,
-                  interpret=_default_interpret() if interpret is None else interpret)
+                  k_block=k_block, interpret=_interpret(interpret))
 
 
 def nystrom_ihvp_apply(C, H_KK, v, rho: float, *, interpret: bool | None = None):
-    """Full Eq. 6 apply through the kernel pipeline:
+    """Full Eq. 6 apply through the kernel pipeline for C (k, p):
     t = Cᵀv (kernel) → w = solve(H_KK + CᵀC/ρ, t) (replicated k×k) →
-    u = v/ρ − C w/ρ² (kernel). One C-read per pass."""
+    u = v/ρ − Cᵀw/ρ² (kernel). One C-read per pass."""
     import jax.numpy as jnp
     t = woodbury_ctv(C, v, interpret=interpret)
     gram = nystrom_gram(C, interpret=interpret)

@@ -6,27 +6,27 @@ import jax.numpy as jnp
 
 
 def nystrom_gram(C: jax.Array) -> jax.Array:
-    """CᵀC for tall-skinny C (p, k) → (k, k), f32 accumulation."""
+    """CᵀC for the sketch-major C (k, p) → (k, k), f32 accumulation."""
     Cf = C.astype(jnp.float32)
-    return Cf.T @ Cf
+    return Cf @ Cf.T
 
 
 def nystrom_cross(A: jax.Array, B: jax.Array) -> jax.Array:
-    """AᵀB : (p, k), (p, m) → (k, m), f32 accumulation."""
-    return A.astype(jnp.float32).T @ B.astype(jnp.float32)
+    """A Bᵀ : (k, p), (m, p) → (k, m), f32 accumulation."""
+    return A.astype(jnp.float32) @ B.astype(jnp.float32).T
 
 
 def woodbury_ctv(C: jax.Array, v: jax.Array) -> jax.Array:
-    """t = Cᵀ v : (p, k), (p,) → (k,) — or (p, m) → (k, m) for a block."""
-    return C.astype(jnp.float32).T @ v.astype(jnp.float32)
+    """t = Cᵀ v : (k, p), (p,) → (k,) — or (m, p) → (k, m) for a block."""
+    return C.astype(jnp.float32) @ v.astype(jnp.float32).T
 
 
 def woodbury_apply(C: jax.Array, w: jax.Array, v: jax.Array,
                    rho: float) -> jax.Array:
-    """u = v/ρ − C w / ρ² : the p-dimensional Woodbury correction apply
-    (vector w (k,), v (p,) — or block w (k, m), v (p, m))."""
+    """u = v/ρ − Cᵀw / ρ² for C (k, p): the p-dimensional Woodbury
+    correction apply (vector w (k,), v (p,) — or block w (k, m), v (m, p))."""
     vf = v.astype(jnp.float32)
-    corr = C.astype(jnp.float32) @ w.astype(jnp.float32)
+    corr = (C.astype(jnp.float32).T @ w.astype(jnp.float32)).T
     return vf / rho - corr / (rho * rho)
 
 

@@ -1,21 +1,21 @@
 """Pallas TPU kernels for the Woodbury IHVP apply (Eq. 6's two p-passes).
 
-Pass 1 — ``ctv``:    t = Cᵀ v           (p, k) × (p,)  → (k,)
-Pass 2 — ``apply``:  u = v/ρ − C w/ρ²   (p, k) × (k,)  → (p,)
+Pass 1 — ``ctv``:    t = Cᵀ v           (k, p) · (p,)  → (k,)
+Pass 2 — ``apply``:  u = v/ρ − Cᵀw/ρ²   (k, p) · (k,)  → (p,)
 
-Both stream C over a p-blocked grid exactly like nystrom_gram (one HBM read
-of C per pass, VMEM-resident k-vector), so a full Nyström IHVP apply costs
-2 C-reads — the paper's "no iterations" property in memory-traffic form;
-compare l sequential HVPs (l full fwd+bwd passes) for CG/Neumann.
+C is the sketch-major (k, p) buffer ``FlatBackend`` builds, read in place:
+both passes stream it over a p-blocked grid exactly like nystrom_gram — one
+(k, block_p) slab per step, one HBM read of C per pass, no padded or
+transposed copy — so a full Nyström IHVP apply costs 2 C-reads, the paper's
+"no iterations" property in memory-traffic form; compare l sequential HVPs
+(l full fwd+bwd passes) for CG/Neumann.
 
-The k-vectors are carried as (1, k_pad) 2-D tiles (TPU VREG lanes want the
-trailing dim = 128-multiple; rank-1 arrays don't map to the vector unit).
-
-Matrix-valued queries: both entry points also take a (p, m) query block
-(``v.ndim == 2``), turning each pass into a genuine GEMM — pass 1 routes to
-``nystrom_cross`` (the gram kernel's two-operand form), pass 2 to a block
-kernel tiling (block_p, m_pad) output slabs. m = 1 (a 1-D ``v``) takes the
-original vector kernels untouched, so existing callers see identical bits.
+Matrix-valued queries: both entry points also take a query block stored
+query-major, v (m, p), like the sketch, so p stays on the 128-wide lane axis
+(see nystrom_gram). Pass 1 is then ``nystrom_cross`` and pass 2 writes
+(m, block_p) output slabs, each one (m, k) @ (k, block_p) MXU matmul. A
+vector is the block with m = 1: v (p,) travels as a (1, p) row, a free
+reshape. Output columns past the end of p are never written back.
 """
 from __future__ import annotations
 
@@ -25,136 +25,55 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-
-def _ctv_kernel(c_ref, v_ref, out_ref):
-    step = pl.program_id(0)
-
-    @pl.when(step == 0)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    c = c_ref[...].astype(jnp.float32)              # (block_p, k_pad)
-    v = v_ref[...].astype(jnp.float32)              # (1, block_p)
-    out_ref[...] += jax.lax.dot_general(
-        v, c, (((1,), (0,)), ((), ())),             # (1,bp) @ (bp,k) → (1,k)
-        preferred_element_type=jnp.float32)
+from repro.kernels.nystrom_gram import nystrom_cross
 
 
 @functools.partial(jax.jit, static_argnames=('block_p', 'interpret'))
 def woodbury_ctv(C: jax.Array, v: jax.Array, *, block_p: int = 1024,
                  interpret: bool = False) -> jax.Array:
-    """t = Cᵀv. v (p,) → (k,) via the vector kernel; v (p, m) → (k, m) via
-    the two-operand gram kernel (one C-read for the whole query block)."""
+    """t = Cᵀv for C (k, p): v (p,) → (k,), or a block v (m, p) → (k, m);
+    one C-read either way."""
     if v.ndim == 2:
-        from repro.kernels.nystrom_gram import nystrom_cross
         return nystrom_cross(C, v, block_p=block_p, interpret=interpret)
-    p, k = C.shape
-    k_pad = max(128, ((k + 127) // 128) * 128)
-    p_pad = ((p + block_p - 1) // block_p) * block_p
-    if (p_pad, k_pad) != (p, k):
-        C = jnp.pad(C, ((0, p_pad - p), (0, k_pad - k)))
-    if p_pad != p:
-        v = jnp.pad(v, (0, p_pad - p))
-    out = pl.pallas_call(
-        _ctv_kernel,
-        grid=(p_pad // block_p,),
-        in_specs=[pl.BlockSpec((block_p, k_pad), lambda i: (i, 0)),
-                  pl.BlockSpec((1, block_p), lambda i: (0, i))],
-        out_specs=pl.BlockSpec((1, k_pad), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((1, k_pad), jnp.float32),
-        interpret=interpret,
-    )(C, v[None, :])
-    return out[0, :k]
+    t = nystrom_cross(C, v.reshape(1, -1), block_p=block_p,
+                      interpret=interpret)
+    return t.reshape(-1)
 
 
-def _make_apply_kernel(rho: float):
-    inv_rho = 1.0 / rho
-    inv_rho2 = 1.0 / (rho * rho)
-
-    def kernel(c_ref, v_ref, w_ref, out_ref):
-        c = c_ref[...].astype(jnp.float32)          # (block_p, k_pad)
-        v = v_ref[...].astype(jnp.float32)          # (1, block_p)
-        w = w_ref[...].astype(jnp.float32)          # (1, k_pad)
-        corr = jax.lax.dot_general(
-            c, w, (((1,), (1,)), ((), ())),         # (bp,k) @ (k,1)ᵀ → (bp,1)
-            preferred_element_type=jnp.float32)
-        out_ref[...] = v * inv_rho - corr.T * inv_rho2
-
-    return kernel
-
-
-def _make_apply_block_kernel(rho: float):
-    inv_rho = 1.0 / rho
-    inv_rho2 = 1.0 / (rho * rho)
-
-    def kernel(c_ref, v_ref, w_ref, out_ref):
-        c = c_ref[...].astype(jnp.float32)          # (block_p, k_pad)
-        v = v_ref[...].astype(jnp.float32)          # (block_p, m_pad)
-        w = w_ref[...].astype(jnp.float32)          # (k_pad, m_pad)
-        corr = jax.lax.dot_general(
-            c, w, (((1,), (0,)), ((), ())),         # (bp,k) @ (k,m) → (bp,m)
-            preferred_element_type=jnp.float32)
-        out_ref[...] = v * inv_rho - corr * inv_rho2
-
-    return kernel
+def _apply_kernel(c_ref, v_ref, w_ref, out_ref, *, rho: float):
+    c = c_ref[...].astype(jnp.float32)              # (k, block_p)
+    v = v_ref[...].astype(jnp.float32)              # (m, block_p)
+    w = w_ref[...].astype(jnp.float32)              # (m, k)
+    corr = jax.lax.dot_general(
+        w, c, (((1,), (0,)), ((), ())),             # (m,k) @ (k,bp) → (m,bp)
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)
+    out_ref[...] = v * (1.0 / rho) - corr * (1.0 / (rho * rho))
 
 
 @functools.partial(jax.jit, static_argnames=('rho', 'block_p', 'interpret'))
 def woodbury_apply(C: jax.Array, w: jax.Array, v: jax.Array, rho: float, *,
                    block_p: int = 1024, interpret: bool = False) -> jax.Array:
-    """u = v/ρ − C w / ρ². ρ is a compile-time constant (hyperparam).
+    """u = v/ρ − Cᵀw/ρ² for C (k, p). ρ is a compile-time constant
+    (hyperparam).
 
     Vector form: w (k,), v (p,) → (p,). Block form (``v.ndim == 2``):
-    w (k, m), v (p, m) → (p, m) — the correction becomes one
-    (block_p, k) @ (k, m) MXU matmul per grid step, still one C-read total.
+    w (k, m), v (m, p) → (m, p) — still one C-read for all m queries.
     """
-    if v.ndim == 2:
-        return _woodbury_apply_block(C, w, v, rho, block_p=block_p,
-                                     interpret=interpret)
-    p, k = C.shape
-    k_pad = max(128, ((k + 127) // 128) * 128)
-    p_pad = ((p + block_p - 1) // block_p) * block_p
-    if (p_pad, k_pad) != (p, k):
-        C = jnp.pad(C, ((0, p_pad - p), (0, k_pad - k)))
-    if p_pad != p:
-        v = jnp.pad(v, (0, p_pad - p))
-    if k_pad != k:
-        w = jnp.pad(w, (0, k_pad - k))
-    out = pl.pallas_call(
-        _make_apply_kernel(rho),
-        grid=(p_pad // block_p,),
-        in_specs=[pl.BlockSpec((block_p, k_pad), lambda i: (i, 0)),
-                  pl.BlockSpec((1, block_p), lambda i: (0, i)),
-                  pl.BlockSpec((1, k_pad), lambda i: (0, 0))],
-        out_specs=pl.BlockSpec((1, block_p), lambda i: (0, i)),
-        out_shape=jax.ShapeDtypeStruct((1, p_pad), jnp.float32),
+    k, p = C.shape
+    if v.ndim == 1:
+        return woodbury_apply(C, w.reshape(k, 1), v.reshape(1, p), rho,
+                              block_p=block_p, interpret=interpret
+                              ).reshape(p)
+    m = v.shape[0]
+    block_p = min(block_p, p)
+    slab = pl.BlockSpec((m, block_p), lambda i: (0, i))
+    return pl.pallas_call(
+        functools.partial(_apply_kernel, rho=rho),
+        grid=(pl.cdiv(p, block_p),),
+        in_specs=[pl.BlockSpec((k, block_p), lambda i: (0, i)), slab,
+                  pl.BlockSpec((m, k), lambda i: (0, 0))],
+        out_specs=slab,
+        out_shape=jax.ShapeDtypeStruct((m, p), jnp.float32),
         interpret=interpret,
-    )(C, v[None, :], w[None, :])
-    return out[0, :p]
-
-
-def _woodbury_apply_block(C: jax.Array, w: jax.Array, v: jax.Array,
-                          rho: float, *, block_p: int,
-                          interpret: bool) -> jax.Array:
-    p, k = C.shape
-    m = v.shape[1]
-    k_pad = max(128, ((k + 127) // 128) * 128)
-    m_pad = max(128, ((m + 127) // 128) * 128)
-    p_pad = ((p + block_p - 1) // block_p) * block_p
-    if (p_pad, k_pad) != (p, k):
-        C = jnp.pad(C, ((0, p_pad - p), (0, k_pad - k)))
-    if (p_pad, m_pad) != v.shape:
-        v = jnp.pad(v, ((0, p_pad - p), (0, m_pad - m)))
-    if (k_pad, m_pad) != w.shape:
-        w = jnp.pad(w, ((0, k_pad - k), (0, m_pad - m)))
-    out = pl.pallas_call(
-        _make_apply_block_kernel(rho),
-        grid=(p_pad // block_p,),
-        in_specs=[pl.BlockSpec((block_p, k_pad), lambda i: (i, 0)),
-                  pl.BlockSpec((block_p, m_pad), lambda i: (i, 0)),
-                  pl.BlockSpec((k_pad, m_pad), lambda i: (0, 0))],
-        out_specs=pl.BlockSpec((block_p, m_pad), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((p_pad, m_pad), jnp.float32),
-        interpret=interpret,
-    )(C, v, w)
-    return out[:p, :m]
+    )(C, v, w.T)

@@ -31,32 +31,27 @@ Hardware model (TPU v5e-class, per chip): 197 TFLOP/s bf16, 819 GB/s HBM,
 from __future__ import annotations
 
 import dataclasses
-import re
 from typing import Any
 
 import jax
 import jax.numpy as jnp
 
+from repro.analysis.auditor import (HLO_COLLECTIVES, HLO_SHAPE_RE,
+                                    hlo_collective_ops)
 from repro.models.config import ModelConfig
 
 PEAK_FLOPS = 197e12
 HBM_BW = 819e9
 ICI_BW = 50e9
 
-_COLLECTIVES = ('all-reduce', 'all-gather', 'reduce-scatter', 'all-to-all',
-                'collective-permute')
 _DTYPE_BYTES = {'f64': 8, 'f32': 4, 'f16': 2, 'bf16': 2, 's64': 8, 'u64': 8,
                 's32': 4, 'u32': 4, 's16': 2, 'u16': 2, 's8': 1, 'u8': 1,
                 'pred': 1, 'f8e4m3fn': 1, 'f8e5m2': 1}
-_SHAPE_RE = re.compile(r'(\w+)\[([\d,]*)\]')
-_LINE_RE = re.compile(
-    r'^\s*(?:ROOT\s+)?%[\w.-]+\s*=\s*(\([^)]*\)|[^=(]+?)\s+('
-    + '|'.join(_COLLECTIVES) + r')(?:-start|-done)?\(')
 
 
 def _type_bytes(type_str: str) -> int:
     total = 0
-    for dtype, dims in _SHAPE_RE.findall(type_str):
+    for dtype, dims in HLO_SHAPE_RE.findall(type_str):
         if dtype not in _DTYPE_BYTES:
             continue
         n = 1
@@ -70,16 +65,11 @@ def _type_bytes(type_str: str) -> int:
 def collective_bytes(hlo_text: str) -> dict[str, Any]:
     """Per-device bytes by collective kind from optimized HLO text.
     `-start` variants counted, `-done` skipped (same transfer)."""
-    out = {k: 0 for k in _COLLECTIVES}
-    counts = {k: 0 for k in _COLLECTIVES}
-    for line in hlo_text.splitlines():
-        if '-done(' in line:
-            continue
-        m = _LINE_RE.match(line)
-        if not m:
-            continue
-        out[m.group(2)] += _type_bytes(m.group(1))
-        counts[m.group(2)] += 1
+    out = {k: 0 for k in HLO_COLLECTIVES}
+    counts = {k: 0 for k in HLO_COLLECTIVES}
+    for kind, type_str, _ in hlo_collective_ops(hlo_text):
+        out[kind] += _type_bytes(type_str)
+        counts[kind] += 1
     return {'bytes': out, 'counts': counts,
             'total_bytes': sum(out.values())}
 
@@ -236,7 +226,7 @@ def assemble(arch: str, shape: str, mesh_name: str, n_chips: int,
     bytes_ = diff(c1['bytes'], c2['bytes']) + corr['bytes']
     coll = diff(float(coll1['total_bytes']), float(coll2['total_bytes']))
     detail = {k: diff(float(coll1['bytes'][k]), float(coll2['bytes'][k]))
-              for k in _COLLECTIVES}
+              for k in HLO_COLLECTIVES}
     return CellAnalysis(
         arch=arch, shape=shape, mesh=mesh_name, n_chips=n_chips,
         flops_per_chip=flops, bytes_per_chip=bytes_,

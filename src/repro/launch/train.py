@@ -10,7 +10,12 @@ balanced validation batch (§5.4 at LM scale).
 Fault-tolerance drill: kill the process mid-run and relaunch with the same
 --ckpt-dir — it resumes from the last durable step (restores across a
 *different* device count thanks to reshard-on-restore). See
-tests/test_trainer.py for the automated version of that drill.
+tests/test_substrate.py::test_trainer_restart_resumes for the automated
+version of that drill.
+
+The LM loop itself is :func:`train_lm` (any ``ModelConfig``); ``main``
+resolves ``--arch``/``--reduced`` to one and calls it. ``chip_smoke.py`` at
+the repository root calls it on a depth-cut yi-9b at full width.
 
 Usage (CPU smoke):
   PYTHONPATH=src python -m repro.launch.train --arch yi_9b --reduced \
@@ -19,7 +24,10 @@ Usage (CPU smoke):
 from __future__ import annotations
 
 import argparse
+import dataclasses
+import functools
 import time
+from typing import Any
 
 import jax
 import jax.numpy as jnp
@@ -27,12 +35,15 @@ import jax.numpy as jnp
 from repro.checkpoint import CheckpointManager
 from repro.configs import get_config
 from repro.core import SketchPolicy, config_from_cli, implicit_root
+from repro.core.tree_util import tree_norm
 from repro.data.loader import Prefetcher, ShardedLoader
 from repro.data.synthetic import TokenStream
 from repro.distributed.ctx import activation_mesh
+from repro.launch.cache import use_compile_cache
 from repro.launch.mesh import make_host_mesh, make_production_mesh
 from repro.launch.steps import N_DOMAINS, make_optimizer
 from repro.models import build_model
+from repro.models.config import ModelConfig
 from repro.models.transformer import train_loss
 from repro.optim import adam
 
@@ -171,7 +182,7 @@ def _serve_problem(problem, hg_cfg, args):
     return service
 
 
-def main(argv=None):
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument('--arch', default='yi_9b')
     ap.add_argument('--reduced', action='store_true',
@@ -212,22 +223,42 @@ def main(argv=None):
     ap.add_argument('--ckpt-every', type=int, default=100)
     ap.add_argument('--production-mesh', action='store_true')
     ap.add_argument('--log-every', type=int, default=10)
-    args = ap.parse_args(argv)
+    return ap
 
-    if args.problem is not None:
-        return _run_problem(args)
 
-    cfg = get_config(args.arch)
-    if args.reduced:
-        cfg = cfg.reduced()
+@dataclasses.dataclass
+class LMRun:
+    """What one :func:`train_lm` run observed, on the host.
+
+    ``losses``: the inner loss of every step this run took (a resumed run
+    starts at its checkpoint). ``outer``: one dict per outer step — ``step``,
+    ``val`` (pre-update outer loss), ``noisy_weight`` (softmax mass on the
+    noisy domains) and ``hypergrad_norm``. ``compile_s``: seconds spent
+    compiling each jitted step, kept apart from the step times.
+    ``peak_bytes_in_use``: the device allocator's peak, where the backend
+    reports one (None elsewhere).
+    """
+    losses: list
+    outer: list
+    hparams: Any
+    compile_s: dict
+    peak_bytes_in_use: int | None
+
+
+def train_lm(cfg: ModelConfig, args) -> LMRun:
+    """The bilevel LM loop on ``cfg``: ``args.steps`` inner steps, an outer
+    Nyström hypergradient step every ``args.outer_every`` of them, with
+    checkpoint/resume under ``args.ckpt_dir``. ``args`` is the namespace
+    :func:`build_parser` parses; ``--arch``/``--reduced`` are read by
+    :func:`main` only, which resolves them to ``cfg``."""
     model = build_model(cfg)
     mesh = (make_production_mesh() if args.production_mesh
             else make_host_mesh())
     print(f'[train] arch={cfg.name} params~{cfg.param_count()/1e6:.1f}M '
           f'mesh={dict(mesh.shape)} devices={len(jax.devices())}')
-
     inner_loss, outer_loss = build_losses(cfg)
     optimizer = make_optimizer(cfg)
+    outer_opt = adam(1e-2)
     # registry-driven flag forwarding: explicitly-passed flags the solver
     # does not consume are rejected loudly by build(), never silently dropped
     hg_cfg = config_from_cli(
@@ -237,34 +268,7 @@ def main(argv=None):
         defaults={'k': 8, 'rho': 1e-2},
         column_chunk=4)
 
-    rng = jax.random.PRNGKey(0)
-    params = model.init(rng)
-    opt_state = optimizer.init(params)
-    hparams = {'domain_logits': jnp.zeros((N_DOMAINS,), jnp.float32)}
-    outer_opt = adam(1e-2)
-    outer_state = outer_opt.init(hparams)
-    step = jnp.int32(0)
-
-    # ---------------- checkpoint/resume (fault tolerance) ----------------
-    ckpt = CheckpointManager(args.ckpt_dir) if args.ckpt_dir else None
-    start_step = 0
-    if ckpt and ckpt.latest_step() is not None:
-        tree = {'params': params, 'opt': opt_state, 'h': hparams,
-                'houter': outer_state}
-        tree, manifest = ckpt.restore_latest(tree)
-        params, opt_state = tree['params'], tree['opt']
-        hparams, outer_state = tree['h'], tree['houter']
-        start_step = manifest['step']
-        print(f'[train] resumed from step {start_step}')
-        step = jnp.int32(start_step)
-
-    # ---------------- data pipeline (deterministic, step-indexed) --------
-    stream = TokenStream(vocab_size=cfg.vocab_size, seq_len=args.seq)
-    loader = Prefetcher(ShardedLoader(
-        lambda s: stream.batch(s, args.batch), start_step=start_step), depth=2)
-
-    # ---------------- jitted steps ----------------
-    @jax.jit
+    @functools.partial(jax.jit, donate_argnums=(0, 1))
     def inner_step(params, opt_state, hparams, step, batch):
         loss, grads = jax.value_and_grad(inner_loss)(params, hparams, batch)
         params, opt_state = optimizer.apply(grads, opt_state, params, step)
@@ -286,7 +290,9 @@ def main(argv=None):
     else:
         policy = None
 
-    @jax.jit
+    # the sketch state is replaced every outer step: donating it lets the
+    # refreshed sketch reuse its HBM instead of holding two at once
+    @functools.partial(jax.jit, donate_argnums=(7,))
     def outer_step(params, hparams, outer_state, step, inner_b, outer_b, key,
                    sketch_state):
         # the warm-started params are the implicit solution; grad through the
@@ -305,49 +311,128 @@ def main(argv=None):
 
         val, hg = jax.value_and_grad(outer_obj)(hparams)  # val: pre-update g
         hparams, outer_state = outer_opt.apply(hg, outer_state, hparams, step)
-        return hparams, outer_state, val, sketch_state
+        return hparams, outer_state, val, tree_norm(hg), sketch_state
+
+    rng = jax.random.PRNGKey(0)
+    params = model.init(rng)
+    opt_state = optimizer.init(params)
+    hparams = {'domain_logits': jnp.zeros((N_DOMAINS,), jnp.float32)}
+    outer_state = outer_opt.init(hparams)
+    step = jnp.int32(0)
+
+    # ---------------- checkpoint/resume (fault tolerance) ----------------
+    ckpt = CheckpointManager(args.ckpt_dir) if args.ckpt_dir else None
+    start_step = 0
+    if ckpt and ckpt.latest_step() is not None:
+        tree = {'params': params, 'opt': opt_state, 'h': hparams,
+                'houter': outer_state}
+        tree, manifest = ckpt.restore_latest(tree)
+        params, opt_state = tree['params'], tree['opt']
+        hparams, outer_state = tree['h'], tree['houter']
+        start_step = manifest['step']
+        print(f'[train] resumed from step {start_step}')
+        step = jnp.int32(start_step)
+
+    # ---------------- data pipeline (deterministic, step-indexed) --------
+    stream = TokenStream(vocab_size=cfg.vocab_size, seq_len=args.seq)
+    loader = Prefetcher(ShardedLoader(
+        lambda s: stream.batch(s, args.batch), start_step=start_step), depth=2)
+
+    # both step programs are compiled before the loop, so compile time is
+    # reported on its own and never lands inside a step time
+    batch = next(loader)
+    compile_s = {}
+
+    def compile_step(name, fn, *a):
+        t = time.perf_counter()
+        with activation_mesh(mesh):        # the model's sharding hints
+            out = fn.lower(*a).compile()
+        compile_s[name] = time.perf_counter() - t
+        print(f'[train] compiled {name} in {compile_s[name]:.2f}s',
+              flush=True)
+        return out
+
+    inner_step = compile_step('inner_step', inner_step, params,
+                              opt_state, hparams, step, batch)
+    sketch_state = None
+    if any((i + 1) % args.outer_every == 0
+           for i in range(start_step, args.steps)):
+        # a key only for its shape: init_state's rng is eval_shape-only and
+        # lowering reads no values
+        shape_key = jax.random.fold_in(rng, 1)
+        if policy is not None:
+            # structural zeros at max staleness: the first outer step's
+            # lax.cond rebuilds it; costs no HVPs here
+            sketch_state = policy.init_state(
+                params, hparams, batch, shape_key)
+        outer_step = compile_step(
+            'outer_step', outer_step, params, hparams, outer_state,
+            step, batch, batch, shape_key, sketch_state)
 
     # ---------------- loop ----------------
-    t0 = time.time()
-    sketch_state = None
-    with activation_mesh(mesh):
-        for i in range(start_step, args.steps):
+    losses, outer = [], []
+    t_win, n_win = time.perf_counter(), 0
+    for i in range(start_step, args.steps):
+        if i > start_step:
             batch = next(loader)
-            params, opt_state, step, loss = inner_step(
-                params, opt_state, hparams, step, batch)
-            if args.log_every and (i + 1) % args.log_every == 0:
-                rate = (i + 1 - start_step) / (time.time() - t0)
-                print(f'[train] step {i+1} loss={float(loss):.4f} '
-                      f'({rate:.2f} steps/s)', flush=True)
-            if (i + 1) % args.outer_every == 0:
-                outer_b = stream.batch(10_000_000 + i, args.batch,
-                                       clean_only=True)
-                okey = jax.random.PRNGKey(i)
-                if policy is not None and sketch_state is None:
-                    # structural zeros at max staleness: the first outer
-                    # step's lax.cond rebuilds it; costs no HVPs here.
-                    # init_state's rng is eval_shape-only, but fold it
-                    # anyway so the step key is never handed out twice
-                    sketch_state = policy.init_state(
-                        params, hparams, batch, jax.random.fold_in(okey, 1))
-                hparams, outer_state, val, sketch_state = outer_step(
-                    params, hparams, outer_state, jnp.int32(i),
-                    batch, outer_b, okey, sketch_state)
-                w = jax.nn.softmax(hparams['domain_logits'])
-                noisy = float(w[jnp.array(stream.noisy_domains)].sum())
-                print(f'[outer] step {i+1} val(pre-update)={float(val):.4f} '
-                      f'noisy-domain weight={noisy:.3f} '
-                      f'(uniform={len(stream.noisy_domains)/stream.n_domains:.3f})',
-                      flush=True)
-            if ckpt and (i + 1) % args.ckpt_every == 0:
-                ckpt.save(i + 1, {'params': params, 'opt': opt_state,
-                                  'h': hparams, 'houter': outer_state})
+        params, opt_state, step, loss = inner_step(
+            params, opt_state, hparams, step, batch)
+        losses.append(loss)
+        n_win += 1
+        if args.log_every and (i + 1) % args.log_every == 0:
+            loss_f = float(loss)
+            now = time.perf_counter()
+            print(f'[train] step {i+1} loss={loss_f:.4f} '
+                  f'step_s={(now - t_win) / n_win:.4f}', flush=True)
+            t_win, n_win = now, 0
+        if (i + 1) % args.outer_every == 0:
+            t0 = time.perf_counter()
+            outer_b = stream.batch(10_000_000 + i, args.batch,
+                                   clean_only=True)
+            okey = jax.random.PRNGKey(i)
+            hparams, outer_state, val, hg_norm, sketch_state = outer_step(
+                params, hparams, outer_state, jnp.int32(i), batch,
+                outer_b, okey, sketch_state)
+            w = jax.nn.softmax(hparams['domain_logits'])
+            rec = {'step': i + 1, 'val': float(val),
+                   'noisy_weight': float(
+                       w[jnp.array(stream.noisy_domains)].sum()),
+                   'hypergrad_norm': float(hg_norm)}
+            outer.append(rec)
+            outer_s = time.perf_counter() - t0
+            uniform = len(stream.noisy_domains) / w.shape[0]
+            print(f'[outer] step {i+1} val(pre-update)={rec["val"]:.4f} '
+                  f'noisy-domain weight={rec["noisy_weight"]:.3f} '
+                  f'(uniform={uniform:.3f}) '
+                  f'hypergrad_norm={rec["hypergrad_norm"]:.4e} '
+                  f'outer_s={outer_s:.4f}', flush=True)
+            t_win += outer_s          # inner step times exclude it
+        if ckpt and (i + 1) % args.ckpt_every == 0:
+            ckpt.save(i + 1, {'params': params, 'opt': opt_state,
+                              'h': hparams, 'houter': outer_state})
     if ckpt:
         ckpt.save(args.steps, {'params': params, 'opt': opt_state,
                                'h': hparams, 'houter': outer_state})
         ckpt.wait()
-    print(f'[train] done: {args.steps} steps, final loss {float(loss):.4f}')
-    return float(loss), hparams
+    losses = [float(l) for l in losses]
+    stats = jax.devices()[0].memory_stats() or {}
+    peak = stats.get('peak_bytes_in_use')
+    final = f'{losses[-1]:.4f}' if losses else 'n/a'
+    print(f'[train] done: {args.steps} steps, final loss {final}, '
+          f'peak_bytes_in_use={peak}', flush=True)
+    return LMRun(losses=losses, outer=outer, hparams=hparams,
+                 compile_s=compile_s, peak_bytes_in_use=peak)
+
+
+def main(argv=None):
+    use_compile_cache()
+    args = build_parser().parse_args(argv)
+    if args.problem is not None:
+        return _run_problem(args)
+    cfg = get_config(args.arch)
+    if args.reduced:
+        cfg = cfg.reduced()
+    return train_lm(cfg, args)
 
 
 if __name__ == '__main__':

@@ -62,29 +62,12 @@ def _rdot_fwd(x, w, group_sizes, dx_reduce=(), dw_reduce=()):
 def _rdot_bwd(dx_reduce, dw_reduce, res, dy):
     x, w, group_sizes = res
     dx = jax.lax.ragged_dot(dy, jnp.swapaxes(w, 1, 2), group_sizes)
-    if hasattr(jax.lax, 'ragged_dot_general'):
-        rdn = jax.lax.RaggedDotDimensionNumbers(
-            dot_dimension_numbers=(((0,), (0,)), ((), ())),
-            lhs_ragged_dimensions=[0],
-            rhs_group_dimensions=[])
-        dw = jax.lax.ragged_dot_general(x.astype(jnp.float32),
-                                        dy.astype(jnp.float32), group_sizes,
-                                        rdn)
-    else:
-        # jax < 0.5 has no ragged_dot_general: contract each expert's token
-        # segment with a masked dense matmul, one expert at a time via
-        # lax.map. O(N·d) temps (no (E, N, d) one-hot), E× dense FLOPs —
-        # the compat cost of the old API, paid only on old jax.
-        bounds = jnp.cumsum(group_sizes)
-        starts = bounds - group_sizes
-        rows = jnp.arange(x.shape[0])
-        xf, dyf = x.astype(jnp.float32), dy.astype(jnp.float32)
-
-        def one_expert(e):
-            m = ((rows >= starts[e]) & (rows < bounds[e])).astype(jnp.float32)
-            return (xf * m[:, None]).T @ dyf
-
-        dw = jax.lax.map(one_expert, jnp.arange(group_sizes.shape[0]))
+    rdn = jax.lax.RaggedDotDimensionNumbers(
+        dot_dimension_numbers=(((0,), (0,)), ((), ())),
+        lhs_ragged_dimensions=[0],
+        rhs_group_dimensions=[])
+    dw = jax.lax.ragged_dot_general(x.astype(jnp.float32),
+                                    dy.astype(jnp.float32), group_sizes, rdn)
     if dx_reduce:
         dx = jax.lax.psum(dx, dx_reduce)
     if dw_reduce:
@@ -188,7 +171,7 @@ def moe_ffn(params, x, cfg: ModelConfig):
     zero collective), expert weights are TP-split on d_ff over 'model', and
     the only communication is the dense-FFN-equivalent psum of the output.
     """
-    from repro.distributed.ctx import current_mesh, shard_map
+    from repro.distributed.ctx import current_mesh
     B, S, d = x.shape
     N = B * S
     xt = x.reshape(N, d)
@@ -215,7 +198,7 @@ def moe_ffn(params, x, cfg: ModelConfig):
         pspec['shared'] = {'w1': P(None, m0), 'w3': P(None, m0),
                            'w2': P(m0, None)}
 
-    out, aux = shard_map(
+    out, aux = jax.shard_map(
         lambda p_, x_: _moe_local(p_, x_, cfg, (model_axes, batch_axes),
                                   impl='capacity'),
         mesh=mesh,

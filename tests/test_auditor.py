@@ -61,6 +61,21 @@ class TestAudit:
         assert report.collective_nbytes is not None
         assert report.collective_nbytes.get('all-reduce', 0) >= 4 * 4
 
+    def test_tpu_tiled_layouts_parse_in_compiled_hlo(self):
+        """TPU HLO types carry tiled layouts with parentheses
+        (``{1,0:T(4,128)S(1)}``); the collective parsers must read them."""
+        from repro.analysis.auditor import ProgramReport, _parse_hlo
+        from repro.launch.analysis import collective_bytes
+        hlo = ('  %psum.7 = f32[3,4]{1,0:T(4,128)S(1)} all-reduce(%fusion.2), '
+               'channel_id=1, replica_groups={{0,1,2,3}}, '
+               'to_apply=%region_0.0\n')
+        report = ProgramReport(collectives=[], dots=[], host_transfers=[],
+                               custom_vjp_calls=0, constants=[])
+        _parse_hlo(hlo, report)
+        assert report.counts('hlo') == {'all-reduce': 1}
+        assert report.records('all-reduce', 'hlo')[0].shape == (3, 4)
+        assert collective_bytes(hlo)['bytes']['all-reduce'] == 3 * 4 * 4
+
     def test_walks_sub_jaxprs(self):
         """Collectives inside scan/pjit bodies are found recursively."""
         fn = _psum_fn(_mesh1())
@@ -223,7 +238,7 @@ def test_kernel_contract_holds_in_interpret_mode():
     bf16 slabs must upcast before the MXU dot."""
     from repro.kernels import ops
 
-    C = jnp.asarray(np.random.default_rng(0).normal(size=(256, 8)),
+    C = jnp.asarray(np.random.default_rng(0).normal(size=(8, 256)),
                     jnp.float32)
     for dtype in (jnp.float32, jnp.bfloat16):
         gram = functools.partial(ops.nystrom_gram, interpret=True)

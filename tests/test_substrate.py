@@ -77,9 +77,9 @@ class TestCheckpoint:
                 '--ckpt-dir', str(tmp_path), '--log-every', '0']
         train.main(argv + ['--steps', '35'])
         assert latest_step(str(tmp_path)) == 35
-        loss, _ = train.main(argv + ['--steps', '45'])  # resumes at 35
+        run = train.main(argv + ['--steps', '45'])  # resumes at 35
         assert latest_step(str(tmp_path)) == 45
-        assert np.isfinite(loss)
+        assert len(run.losses) == 10 and np.isfinite(run.losses[-1])
 
 
 # --------------------------------------------------------------------- data
