@@ -123,7 +123,8 @@ def _implicit_phi_vjp(solver, inner_loss: InnerLoss, theta: PyTree,
     if state is None:
         hvp = make_hvp(inner_loss, theta, phi, batch)
         state = solver.prepare(hvp, PyTreeIndexer(theta), rng)
-    u = jax.lax.stop_gradient(solver.apply(state, v))
+    with jax.named_scope('ihvp_apply'):          # docs/tracing.md
+        u = jax.lax.stop_gradient(solver.apply(state, v))
 
     # mixed term: ∇_φ ⟨∇_θ f(θ*, φ), u⟩  (= (∂²f/∂φ∂θ)ᵀ u); f32 accumulation
     def inner_grad_dot_u(p):
@@ -133,7 +134,9 @@ def _implicit_phi_vjp(solver, inner_loss: InnerLoss, theta: PyTree,
                                   b.astype(jnp.float32)), g_theta, u))
         return sum(leaves)
 
-    return tree_scale(jax.grad(inner_grad_dot_u)(phi), -1.0)
+    with jax.named_scope('mixed_vjp'):
+        mixed = jax.grad(inner_grad_dot_u)(phi)
+    return tree_scale(mixed, -1.0)
 
 
 def _stop_gradient_arrays(tree) -> PyTree:
@@ -171,9 +174,13 @@ def _implicit_phi_tangent(solver, inner_loss: InnerLoss, theta: PyTree,
     def inner_grad(p):
         return jax.grad(inner_loss, argnums=0)(theta_c, p, batch)
 
-    m_dot = jax.jvp(inner_grad, (phi,), (phi_dot,))[1]
+    # the same scopes as the vjp's: reverse mode runs this rule transposed
+    with jax.named_scope('mixed_vjp'):
+        m_dot = jax.jvp(inner_grad, (phi,), (phi_dot,))[1]
     hvp_sys = make_hvp(inner_loss, theta_c, phi, batch)
-    return tree_scale(tangent_apply(solver, state, hvp_sys, m_dot), -1.0)
+    with jax.named_scope('ihvp_apply'):
+        u = tangent_apply(solver, state, hvp_sys, m_dot)
+    return tree_scale(u, -1.0)
 
 
 def phi_vjp_block(solver, inner_loss: InnerLoss, theta: PyTree,

@@ -257,8 +257,12 @@ class NystromIHVP:
                 diag_weights: jax.Array | None = None) -> NystromSketch:
         be = self._be()
         weights = diag_weights if self.importance_sampling else None
-        idx = indexer.sample_indices(rng, self.k, weights)
-        C_tree = extract_columns(hvp, indexer, idx, self.column_chunk)
+        # named scopes: per-phase device times in a profiler trace
+        # (docs/tracing.md); they change op metadata only
+        with jax.named_scope('column_draw'):
+            idx = indexer.sample_indices(rng, self.k, weights)
+        with jax.named_scope('sketch_hvps'):
+            C_tree = extract_columns(hvp, indexer, idx, self.column_chunk)
         H_KK = indexer.gather(C_tree, idx)
         H_KK = 0.5 * (H_KK + H_KK.T)
         C_op = be.prepare_operand(C_tree)

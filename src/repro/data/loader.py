@@ -13,6 +13,7 @@ import threading
 from typing import Any, Callable, Iterator
 
 import jax
+from jax.profiler import TraceAnnotation
 
 
 class ShardedLoader:
@@ -53,17 +54,26 @@ class ShardedLoader:
 
 
 class Prefetcher:
-    """Bounded background prefetch over any iterator."""
+    """Bounded background prefetch over any iterator.
+
+    Each item's production is a ``data.produce`` profiler span on the
+    prefetch thread, and each wait for one a ``data.wait`` span on the
+    consumer's (docs/tracing.md)."""
 
     _SENTINEL = object()
 
     def __init__(self, it: Iterator[Any], depth: int = 2):
         self.q: queue.Queue = queue.Queue(maxsize=depth)
         self._err = None
+        it = iter(it)
 
         def worker():
             try:
-                for item in it:
+                while True:
+                    with TraceAnnotation('data.produce'):
+                        item = next(it, self._SENTINEL)
+                    if item is self._SENTINEL:
+                        break
                     self.q.put(item)
             except Exception as e:          # surface in consumer thread
                 self._err = e
@@ -77,7 +87,8 @@ class Prefetcher:
         return self
 
     def __next__(self):
-        item = self.q.get()
+        with TraceAnnotation('data.wait'):
+            item = self.q.get()
         if item is self._SENTINEL:
             if self._err:
                 raise self._err

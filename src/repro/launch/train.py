@@ -31,6 +31,7 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from repro.checkpoint import CheckpointManager
 from repro.configs import get_config
@@ -236,13 +237,16 @@ class LMRun:
     noisy domains) and ``hypergrad_norm``. ``compile_s``: seconds spent
     compiling each jitted step, kept apart from the step times.
     ``peak_bytes_in_use``: the device allocator's peak, where the backend
-    reports one (None elsewhere).
+    reports one (None elsewhere); ``peak_bytes_reserved``: the peak it held
+    reserved for programs' temporaries, which ``peak_bytes_in_use`` leaves
+    out (on a TPU, the outer step's reservation; None where not reported).
     """
     losses: list
     outer: list
     hparams: Any
     compile_s: dict
     peak_bytes_in_use: int | None
+    peak_bytes_reserved: int | None
 
 
 def train_lm(cfg: ModelConfig, args) -> LMRun:
@@ -370,46 +374,50 @@ def train_lm(cfg: ModelConfig, args) -> LMRun:
             step, batch, batch, shape_key, sketch_state)
 
     # ---------------- loop ----------------
+    # profiler spans (docs/tracing.md): each step is a `train` step
+    # annotation, the outer batch's build a `train.outer_batch` span
     losses, outer = [], []
     t_win, n_win = time.perf_counter(), 0
     for i in range(start_step, args.steps):
-        if i > start_step:
-            batch = next(loader)
-        params, opt_state, step, loss = inner_step(
-            params, opt_state, hparams, step, batch)
-        losses.append(loss)
-        n_win += 1
-        if args.log_every and (i + 1) % args.log_every == 0:
-            loss_f = float(loss)
-            now = time.perf_counter()
-            print(f'[train] step {i+1} loss={loss_f:.4f} '
-                  f'step_s={(now - t_win) / n_win:.4f}', flush=True)
-            t_win, n_win = now, 0
-        if (i + 1) % args.outer_every == 0:
-            t0 = time.perf_counter()
-            outer_b = stream.batch(10_000_000 + i, args.batch,
-                                   clean_only=True)
-            okey = jax.random.PRNGKey(i)
-            hparams, outer_state, val, hg_norm, sketch_state = outer_step(
-                params, hparams, outer_state, jnp.int32(i), batch,
-                outer_b, okey, sketch_state)
-            w = jax.nn.softmax(hparams['domain_logits'])
-            rec = {'step': i + 1, 'val': float(val),
-                   'noisy_weight': float(
-                       w[jnp.array(stream.noisy_domains)].sum()),
-                   'hypergrad_norm': float(hg_norm)}
-            outer.append(rec)
-            outer_s = time.perf_counter() - t0
-            uniform = len(stream.noisy_domains) / w.shape[0]
-            print(f'[outer] step {i+1} val(pre-update)={rec["val"]:.4f} '
-                  f'noisy-domain weight={rec["noisy_weight"]:.3f} '
-                  f'(uniform={uniform:.3f}) '
-                  f'hypergrad_norm={rec["hypergrad_norm"]:.4e} '
-                  f'outer_s={outer_s:.4f}', flush=True)
-            t_win += outer_s          # inner step times exclude it
-        if ckpt and (i + 1) % args.ckpt_every == 0:
-            ckpt.save(i + 1, {'params': params, 'opt': opt_state,
-                              'h': hparams, 'houter': outer_state})
+        with StepTraceAnnotation('train', step_num=i):
+            if i > start_step:
+                batch = next(loader)
+            params, opt_state, step, loss = inner_step(
+                params, opt_state, hparams, step, batch)
+            losses.append(loss)
+            n_win += 1
+            if args.log_every and (i + 1) % args.log_every == 0:
+                loss_f = float(loss)
+                now = time.perf_counter()
+                print(f'[train] step {i+1} loss={loss_f:.4f} '
+                      f'step_s={(now - t_win) / n_win:.4f}', flush=True)
+                t_win, n_win = now, 0
+            if (i + 1) % args.outer_every == 0:
+                t0 = time.perf_counter()
+                with TraceAnnotation('train.outer_batch'):
+                    outer_b = stream.batch(10_000_000 + i, args.batch,
+                                           clean_only=True)
+                okey = jax.random.PRNGKey(i)
+                hparams, outer_state, val, hg_norm, sketch_state = outer_step(
+                    params, hparams, outer_state, jnp.int32(i), batch,
+                    outer_b, okey, sketch_state)
+                w = jax.nn.softmax(hparams['domain_logits'])
+                rec = {'step': i + 1, 'val': float(val),
+                       'noisy_weight': float(
+                           w[jnp.array(stream.noisy_domains)].sum()),
+                       'hypergrad_norm': float(hg_norm)}
+                outer.append(rec)
+                outer_s = time.perf_counter() - t0
+                uniform = len(stream.noisy_domains) / w.shape[0]
+                print(f'[outer] step {i+1} val(pre-update)={rec["val"]:.4f} '
+                      f'noisy-domain weight={rec["noisy_weight"]:.3f} '
+                      f'(uniform={uniform:.3f}) '
+                      f'hypergrad_norm={rec["hypergrad_norm"]:.4e} '
+                      f'outer_s={outer_s:.4f}', flush=True)
+                t_win += outer_s          # inner step times exclude it
+            if ckpt and (i + 1) % args.ckpt_every == 0:
+                ckpt.save(i + 1, {'params': params, 'opt': opt_state,
+                                  'h': hparams, 'houter': outer_state})
     if ckpt:
         ckpt.save(args.steps, {'params': params, 'opt': opt_state,
                                'h': hparams, 'houter': outer_state})
@@ -417,11 +425,14 @@ def train_lm(cfg: ModelConfig, args) -> LMRun:
     losses = [float(l) for l in losses]
     stats = jax.devices()[0].memory_stats() or {}
     peak = stats.get('peak_bytes_in_use')
+    reserved = stats.get('peak_bytes_reserved')
     final = f'{losses[-1]:.4f}' if losses else 'n/a'
     print(f'[train] done: {args.steps} steps, final loss {final}, '
-          f'peak_bytes_in_use={peak}', flush=True)
+          f'peak_bytes_in_use={peak} peak_bytes_reserved={reserved}',
+          flush=True)
     return LMRun(losses=losses, outer=outer, hparams=hparams,
-                 compile_s=compile_s, peak_bytes_in_use=peak)
+                 compile_s=compile_s, peak_bytes_in_use=peak,
+                 peak_bytes_reserved=reserved)
 
 
 def main(argv=None):
