@@ -74,6 +74,27 @@ def tree_random_like(rng: jax.Array, a: PyTree, scale: float = 1.0) -> PyTree:
 # ---------------------------------------------------------------------------
 # Global flat indexing across a pytree (used for Nyström column selection).
 # ---------------------------------------------------------------------------
+def sample_distinct(rng: jax.Array, n: int, k: int) -> jax.Array:
+    """k distinct int32 indices from range(n), k ≤ n < 2³¹: a uniformly random
+    ordered sample without replacement, in O(k²) scalar work.
+
+    Floyd's algorithm draws a uniform k-subset: step i takes t uniform in
+    [0, j], j = n − k + i, or j itself when t is already taken. Its order is
+    not uniform (the last slot holds j more often than chance), and the
+    chunked apply groups columns by position, so a length-k permutation
+    shuffles it. ``jax.random.choice(replace=False)`` would instead permute
+    all n indices (sorts of length n) to keep k."""
+    keys = jax.random.split(rng, k + 1)
+
+    def pick(i, taken):
+        j = n - k + i
+        t = jax.random.randint(keys[i], (), 0, j + 1, dtype=jnp.int32)
+        return taken.at[i].set(jnp.where(jnp.any(taken == t), j, t))
+
+    taken = jax.lax.fori_loop(0, k, pick, jnp.full((k,), -1, jnp.int32))
+    return jax.random.permutation(keys[k], taken)
+
+
 class PyTreeIndexer:
     """Maps parameter coordinates to one-hot tangent pytrees.
 
@@ -164,19 +185,25 @@ class PyTreeIndexer:
                        weights: jax.Array | None = None) -> dict:
         """k structured indices, uniform over all parameters.
 
-        p < 2³¹: distinct flat indices (replace=False), converted with
-        traced int32 math. Beyond int32 range: leaf ∝ size + per-dim uniform
+        p < 2³¹: min(k, p) distinct flat indices, a uniformly random ordered
+        sample without replacement (``sample_distinct``: O(k²) scalar work,
+        nothing of length p), converted with traced int32 math; at k ≥ p
+        every index once. Beyond int32 range: leaf ∝ size + per-dim uniform
         coordinates — with-replacement across the whole space (collision
         probability ≤ k²/2p, negligible at p ≥ 10⁹ and harmless: a duplicate
         column only lowers the sketch rank by one).
 
         ``weights`` (Drineas–Mahoney diag² sampling, Remark 1) requires a
-        flat weight vector and is only supported when p < 2³¹."""
+        flat weight vector and is only supported when p < 2³¹; that draw,
+        ``jax.random.choice(..., replace=False, p=...)``, is O(p)."""
         if self.total < 2 ** 31:
-            p = None if weights is None else weights / weights.sum()
             kk = min(k, self.total)
-            flat = jax.random.choice(rng, self.total, (kk,), replace=False,
-                                     p=p).astype(jnp.int32)
+            if weights is None:
+                flat = sample_distinct(rng, self.total, kk)
+            else:
+                flat = jax.random.choice(rng, self.total, (kk,), replace=False,
+                                         p=weights / weights.sum()
+                                         ).astype(jnp.int32)
             return self._structure_flat_traced(flat)
         if weights is not None:
             raise ValueError('importance sampling needs p < 2^31')

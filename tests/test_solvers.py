@@ -7,6 +7,7 @@ import pytest
 from repro.core import (CGIHVP, ExactIHVP, NeumannIHVP, NystromIHVP,
                         PyTreeIndexer, make_hvp, nystrom_inverse_dense,
                         tree_random_like)
+from repro.core.tree_util import sample_distinct
 
 PARAMS = {'w': jnp.zeros((8,)), 'b': jnp.zeros((2, 2)), 's': jnp.zeros(())}
 
@@ -230,6 +231,57 @@ class TestIndexer:
         # every sampled coordinate is in range
         table = np.asarray(idxr._dim_table)[np.asarray(idx['leaf'])]
         assert (np.asarray(idx['dims']) < table).all()
+
+    @pytest.mark.parametrize('p,k', [(13, 1), (13, 3), (13, 13),
+                                     (239_087_616, 3)])
+    def test_sample_distinct_in_range(self, p, k):
+        flat = np.asarray(sample_distinct(jax.random.PRNGKey(p + k), p, k))
+        assert flat.shape == (k,) and flat.dtype == np.int32
+        assert len(set(flat.tolist())) == k
+        assert ((flat >= 0) & (flat < p)).all()
+        if k == p:
+            assert sorted(flat.tolist()) == list(range(p))
+
+    @pytest.mark.parametrize('k', [13, 20])
+    def test_sample_indices_at_k_ge_p_takes_every_index_once(self, k):
+        idxr = PyTreeIndexer(PARAMS)
+        pairs = lambda ix: [(int(l), tuple(map(int, d)))  # noqa: E731
+                            for l, d in zip(ix['leaf'], ix['dims'])]
+        got = pairs(idxr.sample_indices(jax.random.PRNGKey(k), k))
+        assert len(got) == idxr.total
+        assert sorted(got) == sorted(pairs(idxr.from_flat(range(idxr.total))))
+
+    @pytest.mark.parametrize('p,k', [(13, 3), (239_087_616, 3)])
+    def test_sample_distinct_jit_vmap_matches_eager(self, p, k):
+        keys = jax.random.split(jax.random.PRNGKey(7), 16)
+        batched = jax.jit(jax.vmap(lambda r: sample_distinct(r, p, k)))(keys)
+        eager = np.stack([sample_distinct(r, p, k) for r in keys])
+        np.testing.assert_array_equal(batched, eager)
+        assert all(len(set(row)) == k for row in eager.tolist())
+
+    def test_sample_distinct_law_is_uniform_ordered(self):
+        """p = 6, k = 3: all 120 ordered triples equally likely (chi-square,
+        119 dof, under its 0.9999 quantile 185.1), each index kept k/p of the
+        time and each position uniform — Floyd's draw unshuffled puts the
+        last candidate in the last slot half of the time."""
+        p, k, n = 6, 3, 24_000
+        keys = jax.random.split(jax.random.PRNGKey(2024), n)
+        draws = np.asarray(jax.jit(jax.vmap(
+            lambda r: sample_distinct(r, p, k)))(keys))
+        codes = (draws[:, 0] * p + draws[:, 1]) * p + draws[:, 2]
+        counts = np.bincount(codes, minlength=p ** 3)
+        triples = [(a * p + b) * p + c for a in range(p) for b in range(p)
+                   for c in range(p) if len({a, b, c}) == 3]
+        assert counts.sum() == counts[triples].sum() == n  # all distinct
+        expected = n / len(triples)
+        chi2 = ((counts[triples] - expected) ** 2 / expected).sum()
+        assert chi2 < 185.1, chi2
+        sd = np.sqrt(expected)
+        assert (np.abs(counts[triples] - expected) < 5 * sd).all()
+        share = np.stack([(draws == i).any(1).mean() for i in range(p)])
+        np.testing.assert_allclose(share, k / p, atol=0.015)
+        marginal = (draws[:, :, None] == np.arange(p)).mean(0)   # (k, p)
+        np.testing.assert_allclose(marginal, 1 / p, atol=0.012)
 
     def test_structured_safe_beyond_int32(self):
         """Index math never forms a global flat offset: a (virtual) tree

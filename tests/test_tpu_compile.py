@@ -109,3 +109,17 @@ def test_pallas_block_apply_transposes_no_query_block(one_chip):
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp <= 2 * block + TEMP_LIMIT, (
         f'{temp / block:.2f} query blocks of temporaries, expected <= 2')
+
+
+def test_column_draw_compiles_for_v5e_in_o_k_temporaries(one_chip):
+    """The sketch's column draw at the yi-9b trainer's p keeps k = 3 indices
+    with O(k) temporaries; a draw that permutes all p indices needs
+    gigabytes here."""
+    from repro.core.tree_util import PyTreeIndexer
+    p, k = 239_087_616, 3
+    indexer = PyTreeIndexer({'w': _sds((p,), jnp.float32, one_chip)})
+    key = _sds((2,), jnp.uint32, one_chip)
+    compiled = jax.jit(lambda r: indexer.sample_indices(r, k)).lower(
+        key).compile()
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 1 << 20, f'{temp / 2**20:.1f} MiB of temporaries'
